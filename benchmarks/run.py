@@ -25,6 +25,9 @@ MODULES = [
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sel = [a for a in sys.argv[1:] if not a.startswith("-")]
     print("name,us_per_call,derived")
     failures = []

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 
 NEG_INF = -1e30
@@ -118,7 +117,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),    # running denominator
             pltpu.VMEM((bq, dh), jnp.float32),   # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

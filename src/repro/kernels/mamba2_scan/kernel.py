@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 
 
@@ -106,7 +105,7 @@ def mamba2_scan(x: jax.Array, dt: jax.Array, b: jax.Array, c: jax.Array,
             jax.ShapeDtypeStruct((bh, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, b, c, a)

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 
 
@@ -80,7 +79,7 @@ def moe_gmm(x: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((1, bm, bn), lambda e_, im, jn, kk: (e_, im, jn)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

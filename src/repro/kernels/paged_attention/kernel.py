@@ -7,7 +7,7 @@ speculative verify step) attend to the slot's block-paged KV ring
 dh]`` behind a per-slot page table) *without* ever materializing the
 gathered ``[slots, ring, kv_heads, dh]`` buffer the XLA path builds.
 The page table and cache lengths ride in as **scalar prefetch** operands
-(``compat.PrefetchScalarGridSpec``); the pools stay in HBM/ANY memory
+(``pltpu.PrefetchScalarGridSpec``); the pools stay in HBM/ANY memory
 and the kernel issues its own page DMAs (``pltpu.make_async_copy``) into
 a 2-deep VMEM ring: while page ``j`` is being scored, page ``j+1``'s
 copy is already in flight (double buffering), so the DMA latency hides
@@ -15,11 +15,15 @@ behind the flash-style online-softmax compute.
 
 **Quantized pools** (``k_scale``/``v_scale`` given): K/V pages are
 stored 8-bit (int8 / fp8_e4m3) with per-page, per-kv-head fp32 scales in
-a parallel scale pool.  Each page's scale rows are DMA'd alongside the
-page and folded in-kernel — the K scale into the scores before the
-softcap, the V scale into the PV accumulation — so the *dequantized*
-page never exists anywhere: HBM holds 8-bit, VMEM holds one 8-bit page
-block, and dequantization is two scalar multiplies per kv head.
+a parallel scale pool.  The wrapper gathers each slot's scales through
+its page table (``[slots, nb * kv_heads]``, 1/(page_size * dh) of the
+page bytes) and hands the kernel one slot's row per grid step as an
+SMEM block; the kernel reads them as scalars and folds them in — the K
+scale into the scores before the softcap, the V scale into the PV
+accumulation — so the *dequantized* page never exists anywhere: HBM
+holds 8-bit, VMEM holds one 8-bit page block, and dequantization is two
+scalar multiplies per kv head.  (Scale rows are ``kv_heads`` lanes wide,
+below the 128-lane VMEM tiling, so they never travel through VMEM.)
 
 Per page the kernel recomputes the ring-validity mask from the same
 formula the XLA path uses (``models/attention.ring_token_positions``):
@@ -51,8 +55,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-from repro.kernels.compat import PrefetchScalarGridSpec as _PrefetchGrid
 
 NEG_INF = -1e30
 
@@ -62,11 +64,8 @@ def _kernel(pt_ref, cl_ref, q_ref, kp_ref, vp_ref, *rest, page_size: int,
             window: Optional[int], softcap: Optional[float], scale: float,
             quantized: bool):
     if quantized:
-        (ks_ref, vs_ref, o_ref, kbuf, vbuf, sbuf,
-         sem_k, sem_v, sem_s, m_ref, l_ref, acc_ref) = rest
-    else:
-        ks_ref = vs_ref = sbuf = sem_s = None
-        o_ref, kbuf, vbuf, sem_k, sem_v, m_ref, l_ref, acc_ref = rest
+        ks_ref, vs_ref, *rest = rest     # [1, 1, nb * hkv] SMEM scales
+    o_ref, kbuf, vbuf, sem_k, sem_v, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
     t = cl_ref[b] - 1                    # newest query's absolute position
     ring = nb * page_size
@@ -77,16 +76,10 @@ def _kernel(pt_ref, cl_ref, q_ref, kp_ref, vp_ref, *rest, page_size: int,
         slot ``slot``.  Reconstructed identically at start and wait time
         (the descriptors are pure functions of their arguments)."""
         p = pt_ref[b, j]
-        cps = [pltpu.make_async_copy(kp_ref.at[p], kbuf.at[slot],
-                                     sem_k.at[slot]),
-               pltpu.make_async_copy(vp_ref.at[p], vbuf.at[slot],
-                                     sem_v.at[slot])]
-        if quantized:
-            cps.append(pltpu.make_async_copy(ks_ref.at[p], sbuf.at[slot, 0],
-                                             sem_s.at[slot, 0]))
-            cps.append(pltpu.make_async_copy(vs_ref.at[p], sbuf.at[slot, 1],
-                                             sem_s.at[slot, 1]))
-        return cps
+        return [pltpu.make_async_copy(kp_ref.at[p], kbuf.at[slot],
+                                      sem_k.at[slot]),
+                pltpu.make_async_copy(vp_ref.at[p], vbuf.at[slot],
+                                      sem_v.at[slot])]
 
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -138,7 +131,7 @@ def _kernel(pt_ref, cl_ref, q_ref, kp_ref, vp_ref, *rest, page_size: int,
                     q[sl], k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale  # [rows, P]
                 if quantized:       # dequant K: fold the page's scale in
-                    s = s * sbuf[slot, 0, kh]
+                    s = s * ks_ref[0, 0, j * hkv + kh]
                 if softcap is not None:
                     s = jnp.tanh(s / softcap) * softcap
                 s = jnp.where(valid, s, NEG_INF)
@@ -156,7 +149,7 @@ def _kernel(pt_ref, cl_ref, q_ref, kp_ref, vp_ref, *rest, page_size: int,
                     p, v, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
                 if quantized:       # dequant V: fold into the PV update
-                    pv = pv * sbuf[slot, 1, kh]
+                    pv = pv * vs_ref[0, 0, j * hkv + kh]
                 acc_ref[sl] = acc_ref[sl] * corr + pv
                 m_ref[sl] = m_new
 
@@ -197,7 +190,7 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array,
         trash=npg - 1, window=window, softcap=softcap, scale=dh ** -0.5,
         quantized=quantized)
     rows = h * s
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [
         pl.BlockSpec((1, rows, dh), lambda i, pt, cl: (i, 0, 0)),
         any_spec,                  # pool_k stays in HBM; kernel DMAs pages
@@ -213,12 +206,16 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array,
         pltpu.SemaphoreType.DMA((2,)),                      # V page DMA
     ]
     if quantized:
-        in_specs += [any_spec, any_spec]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
-        scratch.append(pltpu.VMEM((2, 2, hkv), jnp.float32))  # ks/vs rows
-        sems.append(pltpu.SemaphoreType.DMA((2, 2)))
-    grid_spec = _PrefetchGrid(
+        # each slot's per-page scales, gathered through its table row:
+        # grid step i reads row i from SMEM as scalars
+        scale_spec = pl.BlockSpec((1, 1, nb * hkv),
+                                  lambda i, pt, cl: (i, 0, 0),
+                                  memory_space=pltpu.SMEM)
+        in_specs += [scale_spec, scale_spec]
+        operands += [
+            sc.astype(jnp.float32)[page_table].reshape(b, 1, nb * hkv)
+            for sc in (k_scale, v_scale)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,   # page_table + cache_len feed the DMAs
         grid=(b,),
         in_specs=in_specs,
@@ -233,7 +230,7 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(page_table.astype(jnp.int32), cache_len.astype(jnp.int32), *operands)

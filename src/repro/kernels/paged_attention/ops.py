@@ -19,23 +19,29 @@ Dispatch:
 * ``interpret=True`` — force the Pallas kernel through interpret mode on
   any backend: the parity-debugging path the kernel tests use on CPU.
 
+Under sharding rules with a multi-device mesh (``Engine(rules=...)``)
+the kernel runs inside ``jax.shard_map``: a Mosaic kernel cannot be
+partitioned automatically.  Each device attends for its share of the
+slots (split the way the ``BATCH`` rule splits them) against whole
+pools.
+
 Correctness requires the scheduler invariant that already holds for the
 gather path: within one slot's table row every non-trash entry is a
 distinct physical page (pages shared *across* slots are fine — that is
-prefix sharing).  ``supported()`` is the capability probe engines and
-tests gate on: it runs the real kernel through the Pallas toolchain
-(interpret mode off-TPU) instead of sniffing versions.
+prefix sharing).  There is no capability probe and no fallback: on TPU
+a kernel that fails to compile raises.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.paged_attention.kernel import paged_decode_attention
+from repro.parallel import sharding as sh
 
 NEG_INF = -1e30
 
@@ -153,42 +159,26 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     happens inside whichever lowering runs); see module docstring for
     dispatch."""
     if interpret or _on_tpu():
-        return paged_decode_attention(
-            q, pool_k, pool_v, page_table, cache_len, window=window,
-            softcap=softcap, k_scale=k_scale, v_scale=v_scale,
-            interpret=interpret or not _on_tpu())
+        def kern(q, pool_k, pool_v, page_table, cache_len, k_scale=None,
+                 v_scale=None):
+            return paged_decode_attention(
+                q, pool_k, pool_v, page_table, cache_len, window=window,
+                softcap=softcap, k_scale=k_scale, v_scale=v_scale,
+                interpret=interpret or not _on_tpu())
+
+        args = [q, pool_k, pool_v, page_table, cache_len]
+        if k_scale is not None:
+            args += [k_scale, v_scale]
+        rules = sh.current_rules()
+        if rules is None or rules.mesh is None or rules.mesh.size == 1:
+            return kern(*args)
+        rows = rules.spec_for((sh.BATCH,), (q.shape[0],))  # P() if ragged
+        whole = P()
+        return jax.shard_map(
+            kern, mesh=rules.mesh,
+            in_specs=(rows, whole, whole, rows, rows) + (whole,) * (
+                len(args) - 5),
+            out_specs=rows, check_vma=False)(*args)
     return pool_attention_xla(q, pool_k, pool_v, page_table, cache_len,
                               window=window, softcap=softcap,
                               k_scale=k_scale, v_scale=v_scale)
-
-
-_POOL_DTYPES = {"fp32": jnp.float32, "int8": jnp.int8}
-
-
-@functools.lru_cache(maxsize=None)
-def supported(kv_dtype: str = "fp32") -> bool:
-    """Probe, don't version-sniff: run the smallest real paged-attention
-    kernel through the Pallas toolchain (interpret mode off-TPU), in the
-    pool storage dtype the engine wants (scale operands + in-kernel
-    dequant included for 8-bit dtypes).  API drift (grid-spec /
-    scalar-prefetch / DMA renames beyond what compat.py shims) surfaces
-    here as a clean False instead of a trace-time crash."""
-    try:
-        if kv_dtype == "fp8_e4m3":
-            if not hasattr(jnp, "float8_e4m3fn"):
-                return False
-            pool_dt = jnp.float8_e4m3fn
-        else:
-            pool_dt = _POOL_DTYPES[kv_dtype]
-        q = jnp.zeros((1, 2, 8), jnp.float32)
-        pool = jnp.zeros((3, 4, 1, 8), pool_dt)
-        pt = jnp.asarray([[0, 1]], jnp.int32)
-        cl = jnp.asarray([5], jnp.int32)
-        sc = (jnp.ones((3, 1), jnp.float32)
-              if kv_dtype != "fp32" else None)
-        out = paged_decode_attention(q, pool, pool, pt, cl,
-                                     k_scale=sc, v_scale=sc,
-                                     interpret=not _on_tpu())
-        return out.shape == (1, 2, 8)
-    except Exception:
-        return False

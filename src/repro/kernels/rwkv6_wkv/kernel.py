@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 
 
@@ -97,7 +96,7 @@ def rwkv6_wkv(r, k, v, lw, u, *, chunk: int = 16, interpret: bool = False):
             jax.ShapeDtypeStruct((bh, kk, kk), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((kk, kk), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, lw, u)

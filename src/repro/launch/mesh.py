@@ -1,5 +1,8 @@
 """Production meshes.
 
+``make_serving_mesh`` is the one-axis ``data`` mesh the serving engine
+shards over; tests, the launcher and ``chip_smoke.py`` all build it here.
+
 ``make_production_mesh`` is the spec-mandated entry point: 16x16 = 256 chips
 per pod, and 2x16x16 = 512 chips for the multi-pod dry-run.  It is a
 function (never a module-level constant) so importing this module touches no
@@ -16,25 +19,22 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-
-try:  # AxisType / axis_types= landed after jax 0.4.37; run without it there
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def _auto(n: int):
-    return (AxisType.Auto,) * n if AxisType is not None else None
+def _make_mesh(shape, axes, devices=None):
+    # Auto axes: sharding is propagated by GSPMD from the constraints the
+    # model code places; jax.make_mesh otherwise defaults to Explicit
+    # axes, under which ops like jnp.take reject sharded operands
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
-def _make_mesh(shape, axes):
-    at = _auto(len(axes))
-    if at is None:
-        return jax.make_mesh(shape, axes)
-    try:
-        return jax.make_mesh(shape, axes, axis_types=at)
-    except TypeError:  # pragma: no cover - older make_mesh signature
-        return jax.make_mesh(shape, axes)
+def make_serving_mesh(n: Optional[int] = None):
+    """The 1-D ``("data",)`` mesh ``Engine(rules=...)`` shards slots and
+    page pools over, on the first ``n`` devices (default: all)."""
+    devices = jax.devices()[:n]
+    return _make_mesh((len(devices),), ("data",), devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,7 +1,13 @@
-"""Batched serving driver (smoke scale on CPU; production = dry-run lower).
+"""Batched serving driver.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-7b --smoke \
+    PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-7b \
         --requests 6 --max-new 12
+
+``--smoke`` (the default) serves the arch's ``reduced()`` CPU-scale twin
+with fp32 weights.  ``--no-smoke`` serves the published config at full
+width; on a TPU give it ``--param-dtype bfloat16`` (internlm2-1.8b's
+≈1.9 B parameters take ≈3.8 GB in bf16 on one v5e chip).  Weights are
+random.
 
 ``--warmup`` pre-compiles every full-prefill bucket, the jitted cache
 splice, and the fused decode chunk before the first request arrives, so
@@ -52,7 +58,13 @@ import time
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the arch's reduced() smoke twin (default); "
+                         "--no-smoke serves the published config")
+    ap.add_argument("--param-dtype", choices=["float32", "bfloat16"],
+                    default="float32",
+                    help="weight dtype (activations follow it)")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--slots", type=int, default=4)
@@ -73,8 +85,7 @@ def main() -> None:
                          "('int8'/'fp8_e4m3') store per-page fp32 scales "
                          "alongside and dequantize inside the attention "
                          "read (in-kernel on TPU).  'auto'/'fp32' keep "
-                         "full-precision pools; an unsupported 8-bit "
-                         "dtype falls back to fp32 with a notice")
+                         "full-precision pools")
     ap.add_argument("--no-prefix-sharing", action="store_true",
                     help="disable radix prefix sharing / copy-on-write "
                          "page reuse (exclusive page ownership)")
@@ -84,8 +95,8 @@ def main() -> None:
                          "pool-direct path (Pallas kernel on TPU, "
                          "pool-wide masked attention elsewhere), 'off' "
                          "forces gather-then-attend (parity debugging), "
-                         "'auto' picks the kernel on a probe-passing "
-                         "TPU toolchain")
+                         "'auto' picks the kernel on TPU and gather "
+                         "elsewhere")
     ap.add_argument("--spec-draft", default="off",
                     help="speculative decoding drafter: 'off' (default), "
                          "'ngram' (prompt-lookup, no second model), or a "
@@ -169,6 +180,7 @@ def main() -> None:
     import jax.numpy as jnp
 
     from repro.configs import get_config, reduced
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model_defs
     from repro.models import module as m
     from repro.serve.chaos import ChaosMonkey
@@ -177,9 +189,12 @@ def main() -> None:
 
     from repro.serve.spec import SpecConfig
 
-    cfg = reduced(get_config(args.arch))
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if args.smoke:
+        cfg = reduced(cfg)
     params = m.init_params(model_defs(cfg), jax.random.PRNGKey(0),
-                           jnp.float32)
+                           jnp.dtype(args.param_dtype))
     spec = None
     if args.spec_draft != "off":
         spec = SpecConfig(draft=args.spec_draft, k=args.spec_k)
@@ -213,9 +228,6 @@ def main() -> None:
                  prefill_budget=args.prefill_budget,
                  kv_dtype=args.kv_dtype,
                  trace=args.trace is not None)
-    if eng.kv_dtype != eng.requested_kv_dtype:
-        print(f"kv-dtype: '{eng.requested_kv_dtype}' unsupported on this "
-              f"toolchain -> fp32 pools")
     if args.warmup:
         t0 = time.perf_counter()
         eng.warmup()

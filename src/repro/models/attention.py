@@ -277,7 +277,7 @@ def kv_pool_qmax(pool_dtype) -> Optional[float]:
     dt = jnp.dtype(pool_dtype)
     if dt == jnp.dtype(jnp.int8):
         return 127.0
-    if hasattr(jnp, "float8_e4m3fn") and dt == jnp.dtype(jnp.float8_e4m3fn):
+    if dt == jnp.dtype(jnp.float8_e4m3fn):
         return 448.0
     return None
 

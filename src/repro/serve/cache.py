@@ -77,15 +77,6 @@ STATE = "state"          # constant-size recurrent state (mamba2 / rwkv6)
 KV_DTYPES = ("fp32", "int8", "fp8_e4m3")
 
 
-def kv_dtype_supported(kv_dtype: str) -> bool:
-    """Capability gate: can this jax build store pools in ``kv_dtype``?
-    fp8 needs a toolchain with ``jnp.float8_e4m3fn``; engines fall back
-    to fp32 pools when this is False."""
-    if kv_dtype in ("fp32", "int8"):
-        return True
-    return kv_dtype == "fp8_e4m3" and hasattr(jnp, "float8_e4m3fn")
-
-
 def kv_pool_dtype(kv_dtype: str):
     """jnp dtype the K/V pools are stored in for ``kv_dtype``."""
     if kv_dtype == "int8":
@@ -165,11 +156,6 @@ class CacheSpec:
         if kv_dtype not in KV_DTYPES:
             raise ValueError(
                 f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}")
-        if not kv_dtype_supported(kv_dtype):
-            raise ValueError(
-                f"kv_dtype={kv_dtype!r} is unsupported by this jax build "
-                "(no jnp.float8_e4m3fn); gate on kv_dtype_supported() and "
-                "fall back to fp32 pools")
         if page_size & (page_size - 1):
             # fail HERE with an actionable message: a non-power-of-two
             # page used to survive until the paged-attention kernel's

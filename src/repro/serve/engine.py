@@ -43,6 +43,7 @@ per-token-sync loop as the measurement baseline and equivalence oracle for
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import math
 import time
@@ -51,6 +52,8 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models import (forward_decode, forward_prefill, forward_verify,
@@ -231,22 +234,26 @@ class Executor:
             self._draft_prefill_fn = jax.jit(self._draft_prefill_impl,
                                              static_argnums=(3,))
             admit_impl = self._admit_impl
+        self._state_sh: Optional[Dict[str, Any]] = None
+        admit_impl = self._pinned(admit_impl)
+        chunk_impl = self._pinned(self._chunk_impl)
+        deact_impl = self._pinned(self._deact_impl)
         if donate:
             self._admit_fn = jax.jit(admit_impl, donate_argnums=(0, 1))
             self._splice_fn = jax.jit(self._splice_impl,
                                       donate_argnums=(0,))
-            self._chunk_fn = jax.jit(self._chunk_impl, donate_argnums=(2, 3))
+            self._chunk_fn = jax.jit(chunk_impl, donate_argnums=(2, 3))
             self._free_fn = jax.jit(self._free_impl, donate_argnums=(0,))
             self._copy_fn = jax.jit(self._copy_impl, donate_argnums=(0,),
                                     static_argnums=(3,))
-            self._deact_fn = jax.jit(self._deact_impl, donate_argnums=(0,))
+            self._deact_fn = jax.jit(deact_impl, donate_argnums=(0,))
         else:
             self._admit_fn = jax.jit(admit_impl)
             self._splice_fn = jax.jit(self._splice_impl)
-            self._chunk_fn = jax.jit(self._chunk_impl)
+            self._chunk_fn = jax.jit(chunk_impl)
             self._free_fn = jax.jit(self._free_impl)
             self._copy_fn = jax.jit(self._copy_impl, static_argnums=(3,))
-            self._deact_fn = jax.jit(self._deact_impl)
+            self._deact_fn = jax.jit(deact_impl)
 
     def _ctx(self):
         """Sharding rules are a tracing-time thread-local; enter them for
@@ -254,6 +261,44 @@ class Executor:
         if self._rules is None:
             return contextlib.nullcontext()
         return sh.axis_rules(self._rules)
+
+    def _state_shardings(self, state) -> Optional[Dict[str, Any]]:
+        """On a mesh, one fixed layout for the slot state: per-slot rows
+        over ``BATCH``, the PRNG key and counters replicated.  Every
+        executable takes its state in this layout and returns it in this
+        layout, so a chunk's outputs feed the next chunk without a
+        recompile.  None without a mesh."""
+        rules = self._rules
+        if rules is None or rules.mesh is None:
+            return None
+        if self._state_sh is None:
+            def one(name, x):
+                if name != "key" and x.ndim and x.shape[0] == self.spec.slots:
+                    return rules.sharding_for(
+                        (sh.BATCH,) + (None,) * (x.ndim - 1), x.shape)
+                return NamedSharding(rules.mesh, P())
+            self._state_sh = {k: one(k, v) for k, v in state.items()}
+        return self._state_sh
+
+    def _place_state(self, state):
+        """Host side: move state the host rebuilt (a restored key, a new
+        prefill-budget vector) into the fixed layout; a no-op otherwise."""
+        shardings = self._state_shardings(state)
+        return state if shardings is None else jax.device_put(state,
+                                                              shardings)
+
+    def _pinned(self, impl):
+        """``impl`` with its returned state (the last output) held to the
+        fixed layout."""
+        @functools.wraps(impl)
+        def f(*args):
+            out = impl(*args)
+            state = out[-1] if isinstance(out, tuple) else out
+            shardings = self._state_shardings(state)
+            if shardings is not None:
+                state = jax.lax.with_sharding_constraint(state, shardings)
+            return (*out[:-1], state) if isinstance(out, tuple) else state
+        return f
 
     # ------------------------------------------------------ impls (traced)
     @staticmethod
@@ -667,7 +712,7 @@ class Executor:
 
     def admit(self, cache, state, *args):
         with self._ctx():
-            return self._admit_fn(cache, state, *args)
+            return self._admit_fn(cache, self._place_state(state), *args)
 
     def splice(self, cache, one_cache, slot, start, plen, rows):
         with self._ctx():
@@ -680,7 +725,8 @@ class Executor:
 
     def chunk(self, params, draft_params, cache, state):
         with self._ctx():
-            return self._chunk_fn(params, draft_params, cache, state)
+            return self._chunk_fn(params, draft_params, cache,
+                                  self._place_state(state))
 
     def free_slot(self, cache, slot):
         with self._ctx():
@@ -688,7 +734,7 @@ class Executor:
 
     def deactivate(self, state, slot):
         with self._ctx():
-            return self._deact_fn(state, slot)
+            return self._deact_fn(self._place_state(state), slot)
 
     # ----------------------------------------------------------- telemetry
     @property
@@ -727,13 +773,12 @@ class Engine:
     pools: ``True`` = pool-direct (``kernels/paged_attention``: Pallas
     page streaming on TPU, pool-wide masked attention elsewhere — the
     gather buffer never exists), ``False`` = gather-then-attend, and
-    ``"auto"`` = kernel on a probe-passing TPU toolchain, gather
-    elsewhere.  ``kv_dtype`` selects the pool storage precision
-    (``"fp32"`` | ``"int8"`` | ``"fp8_e4m3"``; ``"auto"`` == fp32): 8-bit
-    pools carry per-page, per-kv-head scales and dequantize inside the
-    attention path — ~4x page-pool capacity at a bounded logit error,
-    with an fp32 fallback when the capability gate fails.  ``spec``
-    turns on speculative decoding (``"ngram"``, a
+    ``"auto"`` = the kernel on TPU, gather elsewhere.  ``kv_dtype``
+    selects the pool storage precision (``"fp32"`` | ``"int8"`` |
+    ``"fp8_e4m3"``; ``"auto"`` == fp32): 8-bit pools carry per-page,
+    per-kv-head scales and dequantize inside the attention path — ~4x
+    page-pool capacity at a bounded logit error.  ``spec`` turns on
+    speculative decoding (``"ngram"``, a
     draft-config name, or a ``serve/spec.SpecConfig``): drafted
     multi-token steps verified in the fused chunk, output
     token-identical at temperature 0 — attention-only archs only
@@ -867,33 +912,23 @@ class Engine:
                        if self.chunked_prefill
                        else (spec_cfg.k if spec_cfg else 0))
         # ---- pool precision (quantized KV page pool).  "auto" == fp32.
-        # An explicitly requested 8-bit dtype falls back to fp32 pools
-        # when the capability gate fails (jax build without fp8, or the
-        # arch has no paged layers to quantize) instead of erroring —
-        # precision is a perf knob, not a correctness contract.
-        requested = "fp32" if kv_dtype == "auto" else kv_dtype
-        if requested not in cache_mod.KV_DTYPES:
+        self.kv_dtype = "fp32" if kv_dtype == "auto" else kv_dtype
+        if self.kv_dtype not in cache_mod.KV_DTYPES:
             raise ValueError(
                 f"kv_dtype must be 'auto' or one of {cache_mod.KV_DTYPES}, "
                 f"got {kv_dtype!r}")
-        self.requested_kv_dtype = requested
-        if not cache_mod.kv_dtype_supported(requested):
-            requested = "fp32"
-        self.kv_dtype = requested
         self.spec = CacheSpec.from_config(
             cfg, slots, max_len, page_size=page_size, num_pages=num_pages,
             spec_tokens=cache_slack, kv_dtype=self.kv_dtype)
         if paged_kernel == "auto":
-            # pool-direct attention is the TPU hot path (compiled Pallas
-            # kernel, gated on the runtime toolchain probe).  Off-TPU the
-            # default stays gather-then-attend: at smoke scale XLA's
-            # fused gather+softmax wins, and the pool-wide lowering only
-            # pays off once the pool is oversubscribed — opt in with
-            # paged_kernel=True (fig14 measures both).
-            from repro.kernels import paged_attention as paged_ops
-            paged_kernel = (self.spec.has_paged
-                            and jax.default_backend() == "tpu"
-                            and paged_ops.supported(self.spec.kv_dtype))
+            # pool-direct attention is the TPU hot path (the compiled
+            # Pallas kernel; a compile failure raises, nothing falls
+            # back).  Off-TPU the default stays gather-then-attend: at
+            # smoke scale XLA's fused gather+softmax wins, and the
+            # pool-wide lowering only pays off once the pool is
+            # oversubscribed — opt in with paged_kernel=True (fig14
+            # measures both).
+            paged_kernel = jax.default_backend() == "tpu"
         self.paged_kernel = bool(paged_kernel) and self.spec.has_paged
         if spec_cfg is not None and not self.spec.has_paged:
             raise ValueError(

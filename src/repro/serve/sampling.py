@@ -83,11 +83,12 @@ def make_slot_state(slots: int, seed: int = 0, hist_cap: int = 0,
     clamps it to ``[1, S]``, so the SLO policy can shrink a batch slot's
     budget at a chunk boundary (one host->device value update) without
     retracing: ``S`` stays the static shape."""
-    zi = jnp.zeros((slots,), jnp.int32)
+    # one buffer per leaf: the engine donates the state to its
+    # executables, and a buffer donated twice in one call is an error
     state = {
-        "tokens": zi,
-        "out_len": zi,
-        "max_new": zi,
+        "tokens": jnp.zeros((slots,), jnp.int32),
+        "out_len": jnp.zeros((slots,), jnp.int32),
+        "max_new": jnp.zeros((slots,), jnp.int32),
         "eos": jnp.full((slots,), -1, jnp.int32),
         "active": jnp.zeros((slots,), bool),
         "temp": jnp.zeros((slots,), jnp.float32),

@@ -8,42 +8,7 @@ import sys
 import textwrap
 from pathlib import Path
 
-import pytest
-
 SRC = str(Path(__file__).resolve().parents[1] / "src")
-
-
-def _multidevice_capable() -> bool:
-    """Probe, don't version-sniff (same pattern as test_kernels): spawn
-    one subprocess that forces 8 host devices and builds the explicit-
-    axis-type mesh every case here relies on.  Any API drift (e.g. a jax
-    build without ``jax.sharding.AxisType``) or a backend that cannot
-    fan out host devices surfaces as a module-level skip instead of a
-    wall of red; the cases are covered on multi-chip CI."""
-    code = textwrap.dedent("""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import jax
-        assert jax.device_count() == 8
-        mesh = jax.make_mesh((4, 2), ("pool", "x"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-        assert dict(mesh.shape) == {"pool": 4, "x": 2}
-        print("OK")
-    """)
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    try:
-        r = subprocess.run([sys.executable, "-c", code], env=env,
-                           capture_output=True, text=True, timeout=300)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
-    return r.returncode == 0 and "OK" in r.stdout
-
-
-pytestmark = pytest.mark.skipif(
-    not _multidevice_capable(),
-    reason="no multi-device-capable jax (8-forced-host-device mesh probe "
-           "failed); distribution behaviour is covered on multi-chip CI")
 
 
 def run_sub(body: str):

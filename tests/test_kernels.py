@@ -15,28 +15,6 @@ from repro.kernels.rwkv6_wkv import rwkv6_wkv, rwkv6_wkv_ref
 KEY = jax.random.PRNGKey(7)
 
 
-def _pallas_capable() -> bool:
-    """Probe, don't version-sniff: run the smallest real kernel through the
-    Pallas toolchain (interpret mode on CPU — the kernel bodies execute on
-    the host; compiled mosaic elsewhere).  Any API drift or missing
-    backend support surfaces here as a module-level skip instead of a
-    wall of red."""
-    try:
-        x = jnp.zeros((128, 128), jnp.float32)
-        out = fused_matmul(x, x, None, block_m=128, block_n=128,
-                           block_k=128,
-                           interpret=jax.default_backend() == "cpu")
-        return out.shape == (128, 128)
-    except Exception:
-        return False
-
-
-pytestmark = pytest.mark.skipif(
-    not _pallas_capable(),
-    reason="no Pallas-capable backend/toolchain (interpret-mode probe "
-           "failed); kernel correctness is covered on TPU CI")
-
-
 def _tol(dtype):
     # f32 tolerance allows k-block accumulation-order differences
     return dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 \

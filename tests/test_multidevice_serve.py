@@ -10,8 +10,6 @@ import sys
 import textwrap
 from pathlib import Path
 
-import pytest
-
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
 
@@ -29,33 +27,6 @@ def _run_sub(body: str) -> str:
     return r.stdout
 
 
-def _two_device_capable() -> bool:
-    """Probe (not version-sniff): can this jax fan the host platform out
-    to 2 devices and build the plain data mesh the serving path uses?"""
-    code = textwrap.dedent("""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-        import jax
-        assert jax.device_count() == 2
-        jax.make_mesh((2,), ("data",))
-        print("OK")
-    """)
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    try:
-        r = subprocess.run([sys.executable, "-c", code], env=env,
-                           capture_output=True, text=True, timeout=300)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
-    return r.returncode == 0 and "OK" in r.stdout
-
-
-pytestmark = pytest.mark.skipif(
-    not _two_device_capable(),
-    reason="cannot force 2 host devices (probe failed); the sharded "
-           "serving path is covered on multi-chip CI")
-
-
 def test_sharded_engine_matches_unsharded_tokens():
     """Slot state + per-group page pools sharded over a 2-way data mesh
     must serve token-identical outputs to the unsharded engine — through
@@ -63,6 +34,7 @@ def test_sharded_engine_matches_unsharded_tokens():
     prompts included), and the fused decode chunk."""
     out = _run_sub("""
         from repro.configs import get_config, reduced
+        from repro.launch.mesh import make_serving_mesh
         from repro.models import model_defs
         from repro.models import module as m
         from repro.parallel import sharding as sh
@@ -80,7 +52,7 @@ def test_sharded_engine_matches_unsharded_tokens():
                                    max_new_tokens=6))
             return {r.rid: r.out_tokens for r in eng.run()}
 
-        mesh = jax.make_mesh((2,), ("data",))
+        mesh = make_serving_mesh(2)
         rules = sh.Rules(table={sh.BATCH: "data", sh.PAGES: "data"},
                          mesh=mesh)
         sharded = Engine(cfg, params, slots=2, max_len=64, rules=rules)
@@ -97,5 +69,68 @@ def test_sharded_engine_matches_unsharded_tokens():
             sharded.spec.widest_group.key]
         assert "data" in str(table.sharding), table.sharding
         print("OK", ps["prefix_hit_rate"])
+    """)
+    assert "OK" in out
+
+
+def test_chip_smoke_sharded_phase_matches_one_device():
+    """``chip_smoke.py --chips 4``'s phase at reduced size on a 2-way
+    data mesh: fp32 tokens identical to the one-device engine, bf16
+    tokens within the reference parity tolerance, page tables sharded
+    over ``data``."""
+    out = _run_sub(f"""
+        import importlib.util
+        from repro.configs import get_config, reduced
+
+        spec = importlib.util.spec_from_file_location(
+            "chip_smoke", {str(Path(SRC).parent / "chip_smoke.py")!r})
+        cs = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(cs)
+        cfg = reduced(get_config(cs.ARCH))
+        eng = cs.sharded_matches_one_device(cfg, 0, 2)
+        assert len(eng.finished) == cs.N_REQUESTS
+        print("OK")
+    """)
+    assert "OK" in out
+
+
+def test_paged_kernel_under_mesh_rules_matches_one_device():
+    """Under sharding rules with a 2-device mesh the Pallas paged-attention
+    kernel runs under shard_map (a Mosaic kernel cannot be partitioned
+    automatically): slot rows split over ``data``, whole pools — and the
+    output equals the one-device kernel's, fp32 and int8 pools alike."""
+    out = _run_sub("""
+        from repro.kernels.paged_attention import paged_attention
+        from repro.launch.mesh import make_serving_mesh
+        from repro.models.attention import quantize_pages
+        from repro.parallel import sharding as sh
+
+        rules = sh.Rules(table={sh.BATCH: "data", sh.PAGES: "data"},
+                         mesh=make_serving_mesh(2))
+        k = jax.random.PRNGKey(0)
+        b, npg = 4, 20
+        q = jax.random.normal(k, (b, 3, 4, 16))
+        pk = jax.random.normal(jax.random.fold_in(k, 1), (npg + 1, 4, 2, 16))
+        pv = jax.random.normal(jax.random.fold_in(k, 2), (npg + 1, 4, 2, 16))
+        pt = jnp.asarray(np.random.RandomState(0).permutation(npg)[:b * 4]
+                         .reshape(b, 4), jnp.int32)
+        cl = jnp.asarray([3, 7, 12, 16], jnp.int32)
+        qk, sk = quantize_pages(pk, jnp.int8)
+        qv, sv = quantize_pages(pv, jnp.int8)
+
+        def attend(q, pk, pv, pt, cl, sc):
+            with sh.axis_rules(rules):
+                return paged_attention(q, pk, pv, pt, cl, interpret=True,
+                                       **sc)
+
+        for pools, sc in (((pk, pv), {}),
+                          ((qk, qv), dict(k_scale=sk, v_scale=sv))):
+            want = paged_attention(q, *pools, pt, cl, interpret=True, **sc)
+            got = jax.jit(attend)(q, *pools, pt, cl, sc)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-6, atol=1e-6)
+            assert "manual_computation" in jax.jit(attend).lower(
+                q, *pools, pt, cl, sc).as_text()
+        print("OK")
     """)
     assert "OK" in out

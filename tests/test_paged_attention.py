@@ -3,10 +3,8 @@ page sizes, ring widths, GQA ratios, un-aligned offsets, and trash-page
 masking — plus engine-level token parity and the CacheSpec page-size
 validation.
 
-The Pallas-kernel tests self-gate on the runtime capability probe
-(``kernels.paged_attention.supported``, interpret mode on CPU); the XLA
-pool-wide lowering and engine tests need no Pallas toolchain and always
-run.
+The Pallas-kernel tests run the kernel in interpret mode on CPU;
+``tests/test_tpu_compile.py`` compiles it for a v5e chip at real widths.
 """
 
 import jax
@@ -16,14 +14,9 @@ import pytest
 
 from repro.kernels.paged_attention import (paged_attention_ref,
                                            paged_decode_attention,
-                                           pool_attention_xla, supported)
+                                           pool_attention_xla)
 
 KEY = jax.random.PRNGKey(11)
-
-needs_pallas = pytest.mark.skipif(
-    not supported(),
-    reason="no Pallas-capable backend/toolchain (interpret-mode probe "
-           "failed); kernel correctness is covered on TPU CI")
 
 
 def _interpret() -> bool:
@@ -47,7 +40,6 @@ def _case(b, h, hkv, dh, page_size, nb, num_pages, seed=0, trash_tail=0):
     return q, pool_k, pool_v, jnp.asarray(pt, jnp.int32)
 
 
-@needs_pallas
 @pytest.mark.parametrize("page_size,nb", [(4, 4), (8, 8), (16, 2)])
 @pytest.mark.parametrize("h,hkv", [(4, 4), (4, 2), (8, 1)])
 @pytest.mark.parametrize("mode", ["full", "window", "softcap"])
@@ -86,7 +78,6 @@ def test_pool_lowering_vs_ref(page_size, nb, h, hkv, mode):
                                rtol=2e-4, atol=2e-4)
 
 
-@needs_pallas
 def test_unaligned_suffix_offsets():
     """Every cache_len in a full ring sweep, page-aligned or not."""
     page_size, nb = 4, 4
@@ -100,7 +91,6 @@ def test_unaligned_suffix_offsets():
                                    rtol=2e-4, atol=2e-4, err_msg=str(cl_val))
 
 
-@needs_pallas
 def test_trash_page_masked():
     """Table entries pointing at the trash page contribute -inf scores:
     corrupting the trash page must not change the output, and an
@@ -146,7 +136,6 @@ def test_multiquery_pool_lowering_vs_ref(s, mode):
                                rtol=2e-4, atol=2e-4)
 
 
-@needs_pallas
 @pytest.mark.parametrize("s", [2, 5])
 @pytest.mark.parametrize("mode", ["full", "window", "softcap"])
 def test_multiquery_kernel_vs_ref(s, mode):
@@ -167,7 +156,6 @@ def test_multiquery_kernel_vs_ref(s, mode):
                                rtol=2e-4, atol=2e-4)
 
 
-@needs_pallas
 def test_multiquery_kernel_page_stale_for_newest_row_only():
     """Regression: a page whose tokens are all outside the NEWEST query
     row's window can still be in-window for earlier draft rows — the
@@ -201,7 +189,6 @@ def test_multiquery_first_row_matches_single_query():
                                np.asarray(single), rtol=1e-5, atol=1e-5)
 
 
-@needs_pallas
 def test_model_paged_decode_step_kernel_vs_gather():
     """models/attention.paged_decode_step with paged_kernel on/off must
     produce the same attention output and pool writes."""
@@ -302,10 +289,10 @@ def test_page_size_rejected_at_spec_construction():
 
 # ---------------------------------------------------------------- quantized
 def _qdtypes():
-    """Pool storage dtypes the toolchain can serve quantized."""
-    from repro.serve.cache import KV_DTYPES, kv_dtype_supported
+    """The quantized (8-bit) pool storage dtypes."""
+    from repro.serve.cache import KV_DTYPES
 
-    return [d for d in KV_DTYPES if d != "fp32" and kv_dtype_supported(d)]
+    return [d for d in KV_DTYPES if d != "fp32"]
 
 
 def _quantize_case(case, kv_dtype):
@@ -326,11 +313,6 @@ def test_quantized_kernel_vs_ref(kv_dtype, mode):
     """In-kernel dequant (scales folded into scores / PV inside the
     Pallas kernel) must match the gather-then-dequant oracle bit-for-bit
     up to fp accumulation order — same quantized pools on both sides."""
-    if kv_dtype not in _qdtypes():
-        pytest.skip(f"{kv_dtype} pools unsupported on this toolchain")
-    if not supported(kv_dtype):
-        pytest.skip("no Pallas-capable backend/toolchain for "
-                    f"{kv_dtype} pools")
     kw = {"full": {}, "window": {"window": 12},
           "softcap": {"softcap": 20.0}}[mode]
     page_size, nb = 4, 4
@@ -352,8 +334,6 @@ def test_quantized_pool_lowering_vs_ref(kv_dtype, s):
     """The XLA pool-wide lowering's folded dequant (scales into scores /
     softmax weights, no fp32 pool ever stored) vs the oracle — single
     and multi-query (speculative verify) row counts."""
-    if kv_dtype not in _qdtypes():
-        pytest.skip(f"{kv_dtype} pools unsupported on this toolchain")
     page_size, nb = 4, 4
     ring = page_size * nb
     q, qk, qv, sk, sv, pt = _quantize_case(
@@ -394,8 +374,6 @@ def test_quantized_trash_page_invariance():
     """Corrupting the trash page AND its scale rows must not change
     quantized-pool attention output; an all-trash row returns exactly
     0 in every lowering."""
-    if not _qdtypes():
-        pytest.skip("no quantized pool dtypes on this toolchain")
     page_size, nb = 8, 4
     q, qk, qv, sk, sv, pt = _quantize_case(
         _case(3, 4, 2, 16, page_size, nb, 3 * nb, seed=3, trash_tail=2),
@@ -417,13 +395,12 @@ def test_quantized_trash_page_invariance():
                                k_scale=bad_sk, v_scale=bad_sv)
     np.testing.assert_allclose(np.asarray(out3), np.asarray(out1),
                                rtol=2e-4, atol=2e-4)
-    if supported("int8"):
-        out4 = paged_decode_attention(q, bad_k, bad_v, pt, cl,
-                                      k_scale=bad_sk, v_scale=bad_sv,
-                                      interpret=_interpret())
-        np.testing.assert_allclose(np.asarray(out4), np.asarray(out1),
-                                   rtol=2e-4, atol=2e-4)
-        np.testing.assert_array_equal(np.asarray(out4[1]), 0.0)
+    out4 = paged_decode_attention(q, bad_k, bad_v, pt, cl,
+                                  k_scale=bad_sk, v_scale=bad_sv,
+                                  interpret=_interpret())
+    np.testing.assert_allclose(np.asarray(out4), np.asarray(out1),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(np.asarray(out4[1]), 0.0)
 
 
 def test_quantized_model_paged_decode_step_kernel_vs_gather():
@@ -432,8 +409,6 @@ def test_quantized_model_paged_decode_step_kernel_vs_gather():
     writes (the RMW write path is shared, so pools must be identical)."""
     from repro.models import attention
 
-    if not _qdtypes():
-        pytest.skip("no quantized pool dtypes on this toolchain")
     b, h, hkv, dh, page_size, nb = 2, 4, 2, 16, 4, 4
     q = jax.random.normal(KEY, (b, 1, h, dh)) * 0.5
     kk = jax.random.normal(jax.random.fold_in(KEY, 1), (b, 1, hkv, dh)) * 0.5
@@ -443,8 +418,6 @@ def test_quantized_model_paged_decode_step_kernel_vs_gather():
     cl = jnp.asarray([6, 13], jnp.int32)
     outs = {}
     for paged_kernel in (False, True):
-        if paged_kernel and not supported("int8"):
-            pytest.skip("no Pallas toolchain for int8 pools")
         cache = {"pk": qk, "pv": qv, "pt": pt, "ks": sk, "vs": sv}
         out, new = attention.paged_decode_step(
             q, kk, vv, cache, cl, window=None, softcap=None,
@@ -467,8 +440,6 @@ def test_quantized_engine_token_parity_kernel_vs_gather():
     from repro.models import module as m
     from repro.serve.engine import Engine
 
-    if "int8" not in _qdtypes():
-        pytest.skip("int8 pools unsupported on this toolchain")
     cfg = reduced(get_config("internlm2-1.8b"))
     params = m.init_params(model_defs(cfg), jax.random.PRNGKey(0),
                            jnp.float32)
@@ -477,8 +448,7 @@ def test_quantized_engine_token_parity_kernel_vs_gather():
     gather = Engine(cfg, params, paged_kernel=False, **kw)
     out_gather = _run_engine(gather)
     assert gather.leaked_pages() == 0
-    if supported("int8"):
-        paged = Engine(cfg, params, paged_kernel=True, **kw)
-        out_paged = _run_engine(paged)
-        assert paged.leaked_pages() == 0
-        assert out_paged == out_gather
+    paged = Engine(cfg, params, paged_kernel=True, **kw)
+    out_paged = _run_engine(paged)
+    assert paged.leaked_pages() == 0
+    assert out_paged == out_gather

@@ -10,6 +10,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config, reduced
+from repro.launch.mesh import make_serving_mesh
 from repro.models import forward_dense_logits, model_defs
 from repro.models import module as m
 from repro.models.attention import ring_token_positions, ring_valid
@@ -440,7 +441,7 @@ def test_cachespec_data_axis_sharding_specs():
 
 def test_engine_accepts_rules_single_device():
     """rules wiring is a no-op on one device but must not change tokens."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_serving_mesh(1)
     rules = sh.Rules(table={sh.BATCH: "data", sh.PAGES: "data"}, mesh=mesh)
     cfg, params = _model("internlm2-1.8b")
     eng = Engine(cfg, params, slots=2, max_len=64, rules=rules)
@@ -454,9 +455,9 @@ def test_engine_accepts_rules_single_device():
 
 # ------------------------------------------------------ quantized KV pools
 def _supported_qdtypes():
-    from repro.serve.cache import KV_DTYPES, kv_dtype_supported
+    from repro.serve.cache import KV_DTYPES
 
-    return [d for d in KV_DTYPES if d != "fp32" and kv_dtype_supported(d)]
+    return [d for d in KV_DTYPES if d != "fp32"]
 
 
 @pytest.mark.parametrize("page_size", [4, 8, 16])
@@ -517,8 +518,6 @@ def test_quantized_splice_parity_with_fp32(kv_dtype):
     from repro.models.attention import dequantize_pages
     from repro.serve import cache as cm
 
-    if kv_dtype not in _supported_qdtypes():
-        pytest.skip(f"{kv_dtype} pools unsupported on this toolchain")
     cfg, _ = _model("internlm2-1.8b")
     spec32 = CacheSpec.from_config(cfg, 2, 64, page_size=8)
     spec8 = CacheSpec.from_config(cfg, 2, 64, page_size=8,
@@ -574,8 +573,6 @@ def test_quantized_copy_shared_page_copies_scales():
     from repro.models.attention import dequantize_pages
     from repro.serve import cache as cm
 
-    if not _supported_qdtypes():
-        pytest.skip("no quantized pool dtypes on this toolchain")
     cfg, _ = _model("internlm2-1.8b")
     spec = CacheSpec.from_config(cfg, 2, 64, page_size=8, kv_dtype="int8")
     C = spec.init_paged_cache()
@@ -617,20 +614,18 @@ def test_quantized_spec_memory_accounting():
 
 
 def test_engine_kv_dtype_validation_and_fallback():
-    """Engine(kv_dtype=...): unknown names raise; 'auto' is fp32; an
-    unsupported 8-bit dtype falls back to fp32 (capability gate, not a
-    crash) while recording what was requested."""
-    from repro.serve import cache as cm
-
+    """Engine(kv_dtype=...): unknown names raise; 'auto' is fp32; every
+    8-bit dtype is served as requested — there is no silent fallback to
+    fp32 pools."""
     cfg, params = _model("internlm2-1.8b")
     with pytest.raises(ValueError, match="kv_dtype"):
         Engine(cfg, params, slots=2, max_len=64, kv_dtype="int4")
     eng = Engine(cfg, params, slots=2, max_len=64, kv_dtype="auto")
     assert eng.kv_dtype == "fp32" and eng.spec.kv_dtype == "fp32"
-    if "int8" in _supported_qdtypes():
-        eng8 = Engine(cfg, params, slots=2, max_len=64, kv_dtype="int8")
-        assert eng8.kv_dtype == "int8" and eng8.spec.quantized
+    for kv_dtype in _supported_qdtypes():
+        eng8 = Engine(cfg, params, slots=2, max_len=64, kv_dtype=kv_dtype)
+        assert eng8.kv_dtype == kv_dtype and eng8.spec.quantized
         stats = eng8.memory_stats()
-        assert stats["kv_dtype"] == "int8"
+        assert stats["kv_dtype"] == kv_dtype
         assert "pool_bytes_per_live_token" in stats
         assert "peak_live_slots" in stats

@@ -363,3 +363,26 @@ def test_per_request_temperature_mixed_batch():
     assert done[0].out_tokens == s.out_tokens
     assert len(done[1].out_tokens) == 6
     assert eng.decode_compiles == 1
+
+
+@pytest.mark.parametrize("arch,kw", [
+    ("internlm2-1.8b", {}),                              # fused chunks
+    ("internlm2-1.8b", {"chunked_prefill": False}),      # bucketed prefill
+    ("internlm2-1.8b", {"spec": "ngram", "kv_dtype": "int8"}),
+    ("rwkv6-7b", {}),
+])
+def test_donating_engine_matches_non_donating(arch, kw):
+    """Engines donate their cache and slot state off CPU.  Every donated
+    leaf must be its own buffer (a buffer donated twice in one call is
+    an error), and donation must not change a token."""
+    cfg, params = _model(arch)
+    outs = []
+    for donate in (False, True):
+        eng = Engine(cfg, params, slots=2, max_len=64, donate=donate, **kw)
+        eng.warmup()
+        for i in range(3):
+            eng.submit(Request(rid=i, prompt=[3 + i, 4, 5, 6, 7],
+                               max_new_tokens=7))
+        outs.append({r.rid: r.out_tokens for r in eng.run()})
+        assert eng.leaked_pages() == 0
+    assert outs[0] == outs[1] and len(outs[1]) == 3
