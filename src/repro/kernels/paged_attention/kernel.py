@@ -233,6 +233,7 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="paged_attention",
     )(page_table.astype(jnp.int32), cache_len.astype(jnp.int32), *operands)
     out = out.reshape(b, hkv, s, g, dh).transpose(0, 2, 1, 3, 4)
     out = out.reshape(b, s, h, dh)

@@ -47,7 +47,7 @@ import functools
 import json
 import math
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1018,6 +1018,8 @@ class Engine:
         # (admission_log 5th element), and every trace event at the
         # boundary — the cross-reference key between all three.
         self.chunks = 0
+        if self.tracer is not None:
+            trace_mod.watch_gc(self, self.tracer, self._clock)
         self.chaos = chaos
         self.scheduler.chaos = chaos
         if chaos is not None and self.tracer is not None:
@@ -1179,6 +1181,14 @@ class Engine:
         self.tracer.record(kind, self._clock() if ts is None else ts,
                            rid=rid, slot=slot, **attrs)
 
+    def _span(self, name: str):
+        """Time one host phase (``serve/trace.span``): a profiler
+        annotation always, a ``span`` event when tracing is on.
+        ``chunk`` is the chunks drained when the phase began, as on
+        ``admit`` events, so the phases of one ``step()`` share it."""
+        return trace_mod.span(self.tracer, name, self._clock,
+                              chunk=self.chunks)
+
     def _chaos_event(self, fault: str, **attrs: Any) -> None:
         slot = attrs.pop("slot", None)
         self._trace("chaos", slot=slot, fault=fault, **attrs)
@@ -1231,7 +1241,11 @@ class Engine:
         sheds only if the queue is still full.  Returns ``None`` when the
         request was accepted.  ``ValueError`` for requests violating the
         ``max_len`` contract still raises — that is a caller bug, not
-        load."""
+        load.  Timed as the host phase ``engine.submit``."""
+        with self._span("engine.submit"):
+            return self._submit(req)
+
+    def _submit(self, req: Request) -> Optional[RequestRejected]:
         if len(req.prompt) + req.max_new_tokens > self.max_len \
                 and not self.cfg.supports_long_context:
             # full-attention page tables cap at max_len tokens; a longer
@@ -1855,32 +1869,69 @@ class Engine:
         rejoin the free list; shared/radix-indexed pages survive for
         their other referents) and the slot's page-table rows are pointed
         at the trash pages, so its dead tail writes cannot touch
-        re-leased pages."""
+        re-leased pages.  The transfer is the host span ``engine.wait``,
+        the rest ``engine.drain``."""
         fetch = (toks, self.state["out_len"], self.state["active"],
                  [self._slot_first_tok[i] for i in range(self.slots)])
-        if self.chunked_prefill:
-            # also drain the prefill cursor (cache["len"], capped by the
-            # prompt length per slot below) in the SAME transfer
-            toks_np, out_len, active, firsts, cache_len = jax.device_get(
-                fetch + (self.cache["len"],))
-        else:
-            toks_np, out_len, active, firsts = jax.device_get(fetch)
-            cache_len = None
+        with self._span("engine.wait"):
+            if self.chunked_prefill:
+                # also drain the prefill cursor (cache["len"], capped by
+                # the prompt length per slot below) in the SAME transfer
+                toks_np, out_len, active, firsts, cache_len = \
+                    jax.device_get(fetch + (self.cache["len"],))
+            else:
+                toks_np, out_len, active, firsts = jax.device_get(fetch)
+                cache_len = None
+        with self._span("engine.drain"):
+            self._settle(toks_np, out_len, active, firsts, cache_len)
+
+    def _rows_drained(self, live: List[int], toks_np, out_len,
+                      cache_len) -> Tuple[int, int]:
+        """``(prefill_rows, decode_rows)`` of the chunk just fetched, for
+        the ``chunk`` event: prompt rows written (each live slot's
+        prefill-cursor advance below its prompt length) and rows that
+        decoded a token (tokens in the history, less the first token a
+        prefill row sampled when a slot's prompt completed)."""
+        prefill = decode = 0
+        for slot in live:
+            first = 0
+            if cache_len is not None:
+                plen0, prev = self._slot_plen[slot], self._slot_seen_len[slot]
+                seen = min(int(cache_len[slot]), plen0)
+                prefill += max(seen - prev, 0)
+                first = int(prev < plen0 <= seen)
+            k = (int(out_len[slot]) - len(self._slot_req[slot].out_tokens)
+                 - int(self._slot_first_pending[slot]))
+            if k > 0:
+                n = min(k, int(np.count_nonzero(toks_np[:, slot] >= 0)))
+                decode += max(n - first, 0)
+        return prefill, decode
+
+    def _settle(self, toks_np, out_len, active, firsts, cache_len) -> None:
+        """Host bookkeeping of one drain, over the fetched arrays."""
         self.host_syncs += 1
         now = self._clock()   # one host clock read stamps every token
         self.chunks += 1      # chunk sequence number for this drain
+        live = [s for s in range(self.slots) if self._slot_req[s] is not None]
+        # an injected straggler reports nothing this boundary (asked once
+        # per slot: the chaos schedule counts the stalled drains)
+        stalled = {s for s in live
+                   if self.chaos is not None and self.chaos.stalled(s)}
         if self.tracer is not None:
+            prefill_rows, decode_rows = self._rows_drained(
+                [s for s in live if s not in stalled], toks_np, out_len,
+                cache_len)
             self._trace("chunk", ts=now, chunk=self.chunks,
                         queue_depth=len(self.scheduler.queue),
                         pages_in_use=self.scheduler.pages_in_use,
-                        live_slots=sum(r is not None
-                                       for r in self._slot_req))
+                        live_slots=len(live),
+                        rows_run=(self.slots * self.executor.chunk_rows
+                                  * self.executor.sync_interval),
+                        prefill_rows=prefill_rows, decode_rows=decode_rows)
         watchdog: List[int] = []
-        for slot in range(self.slots):
+        for slot in live:
             req = self._slot_req[slot]
-            if req is None:
-                continue
-            if self.chaos is not None and self.chaos.stalled(slot):
+            if slot in stalled:
                 # injected straggler: the slot reported nothing this
                 # boundary.  Progress stalls host-side until the watchdog
                 # preempts it; tokens lost in between regenerate on
@@ -1967,35 +2018,46 @@ class Engine:
         (``sync_interval`` decode steps per call).  All policy — deadline
         reaping, cancellation, preemption, admission — runs on the host
         at this boundary; the chunk itself stays one sync-free
-        executable."""
-        self._reap()
-        if self.chaos is not None:
-            live = [i for i in range(self.slots)
-                    if self._slot_req[i] is not None]
-            self.chaos.tick(live)
-            for slot in self.chaos.storm_victims(live):
-                if self.policy == "slo":
-                    # chaos decides THAT a preemption storm hits; under
-                    # the SLO policy the class-aware victim rule decides
-                    # WHO — interactive slots are evicted last, exactly
-                    # as under genuine pool pressure
-                    picked = self._pick_victim()
-                    if picked is None:
-                        continue
-                    slot = picked
-                if self._slot_req[slot] is not None:
-                    self._preempt_slot(slot, "chaos")
-        self._admit()
-        self._update_prefill_budgets()
-        if not self._live():
-            if not self.scheduler.can_progress(0, now=self._clock()):
+        executable.  Each phase is a host span (``_span``):
+        ``engine.reap``, ``engine.admit``, ``engine.dispatch``, then the
+        drain's ``engine.wait`` and ``engine.drain``."""
+        with self._span("engine.reap"):
+            self._reap()
+            if self.chaos is not None:
+                self._chaos_tick()
+        with self._span("engine.admit"):
+            self._admit()
+            self._update_prefill_budgets()
+            live = self._live()
+            if not live and not self.scheduler.can_progress(
+                    0, now=self._clock()):
                 head = self.queue[0]
                 raise PagePoolExhausted(
                     f"wedged: rid={head.rid} cannot be admitted "
                     f"({self.scheduler.pool.free_pages} pages free) and no "
                     "slot is live to release more")
+        if not live:
             return
-        self._drain(self.step_chunk())
+        with self._span("engine.dispatch"):
+            toks = self.step_chunk()
+        self._drain(toks)
+
+    def _chaos_tick(self) -> None:
+        live = [i for i in range(self.slots)
+                if self._slot_req[i] is not None]
+        self.chaos.tick(live)
+        for slot in self.chaos.storm_victims(live):
+            if self.policy == "slo":
+                # chaos decides THAT a preemption storm hits; under
+                # the SLO policy the class-aware victim rule decides
+                # WHO — interactive slots are evicted last, exactly
+                # as under genuine pool pressure
+                picked = self._pick_victim()
+                if picked is None:
+                    continue
+                slot = picked
+            if self._slot_req[slot] is not None:
+                self._preempt_slot(slot, "chaos")
 
     def run(self, max_steps: int = 1000) -> List[Request]:
         while (self.queue or self._live()) and self.steps < max_steps:

@@ -3,11 +3,16 @@
 Every host-side lifecycle transition -- submit, admit, prefill-cursor
 advance, chunk boundary, preempt/resume, copy-on-write, radix hit,
 reap, chaos fault, terminal -- is recorded as one :class:`TraceEvent`
-``(ts, kind, rid, slot, attrs)``.  Events are recorded **only at chunk
-boundaries** by the host driver, timestamped from the engine's
-injectable clock (the single per-drain ``_clock()`` read; a
-``VirtualClock`` under replay), so tracing adds zero device syncs and
-the fused decode chunk stays one compiled executable.  The buffer is a
+``(ts, kind, rid, slot, attrs)``, timestamped from the engine's
+injectable clock (a ``VirtualClock`` under replay).  Lifecycle events
+are recorded by the host driver at chunk boundaries, most from the
+drain's single ``_clock()`` read.  :func:`span` times a host phase of
+the engine (``engine.reap`` ... ``engine.drain``, ``engine.submit``)
+as one ``span`` event and the same span in JAX's profiler, so a device
+trace names what the host did in each gap; :func:`watch_gc` does the
+same for every collection of Python's cyclic collector (``gc``).
+None of it touches the device: tracing adds zero device syncs and the
+fused decode chunk stays one compiled executable.  The buffer is a
 bounded ring: at capacity the oldest non-terminal event is evicted
 (``Tracer.dropped`` counts them) while terminal events (``finish`` /
 ``reject``) are never dropped.
@@ -15,18 +20,25 @@ bounded ring: at capacity the oldest non-terminal event is evicted
 Exporters: :func:`to_chrome_trace` renders the Chrome trace-event /
 Perfetto JSON timeline (per-slot tracks, async queue spans,
 per-request flow arrows across preempt/resume, counter tracks for pool
-occupancy and queue depth) behind ``Engine.export_trace`` and
+occupancy and queue depth, host phase spans on the engine track and
+collector pauses on their own) behind ``Engine.export_trace`` and
 validated by ``benchmarks/check_trace.py``; :func:`explain` renders a
 per-request causal chain with per-phase durations behind
 ``Engine.explain``.  ``Tracer.fingerprint()`` is a canonical string
-over the buffered events -- two replays of the same seeded traffic on
-a ``VirtualClock`` produce byte-identical fingerprints
+over the buffered lifecycle events -- two replays of the same seeded
+traffic on a ``VirtualClock`` produce byte-identical fingerprints
 (``tests/test_trace.py``).
 """
 
 import collections
+import contextlib
 import dataclasses
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+import gc
+import weakref
+from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional, \
+    Tuple
+
+import jax
 
 # The event taxonomy (docs/observability.md documents each kind):
 EVENT_KINDS = (
@@ -42,15 +54,18 @@ EVENT_KINDS = (
     "chaos",       # injected fault fired (serve/chaos.py)
     "finish",      # terminal: FINISHED / TIMED_OUT / CANCELLED
     "reject",      # terminal: shed at submit (infeasible / queue_full)
+    "span",        # timed host phase; attrs: name, dur, chunk
 )
 
 #: Terminal kinds are never evicted from the ring.
 TERMINAL_KINDS = frozenset({"finish", "reject"})
 
-# Chrome-trace thread ids: one engine-wide track for chunk boundaries
-# and counters, one queue track for wait phases, one track per slot.
+# Chrome-trace thread ids: one engine-wide track for chunk boundaries,
+# counters and host phase spans, one queue track for wait phases, one
+# track for collector pauses, one track per slot.
 ENGINE_TID = 0
 QUEUE_TID = 1
+GC_TID = 2
 SLOT_TID_BASE = 10
 
 
@@ -85,9 +100,12 @@ class Tracer:
 
     def record(self, kind: str, ts: float, rid: Optional[int] = None,
                slot: Optional[int] = None, **attrs: Any) -> TraceEvent:
-        ev = TraceEvent(ts=float(ts), kind=kind, rid=rid, slot=slot,
-                        attrs=attrs, seq=self._seq)
+        # take the number first: the allocation below may run a
+        # collection whose ``gc`` span (watch_gc) records in between
+        seq = self._seq
         self._seq += 1
+        ev = TraceEvent(ts=float(ts), kind=kind, rid=rid, slot=slot,
+                        attrs=attrs, seq=seq)
         self._ring.append(ev)
         # Evict oldest-first, but terminal events survive eviction by
         # moving to the pinned list (which may push us past capacity:
@@ -109,16 +127,75 @@ class Tracer:
         return len(self._ring) + len(self._pinned)
 
     def fingerprint(self) -> str:
-        """Canonical string over all buffered events.
+        """Canonical string over the buffered lifecycle events.
 
         Timestamps are ``repr``-ed so replayed ``VirtualClock``
-        experiments compare byte-exact.
+        experiments compare byte-exact.  ``span`` events are left out,
+        and the rest numbered among themselves: they time the host, and
+        a collector pause falls where it will.
         """
         lines = []
-        for e in self.events():
+        evs = [e for e in self.events() if e.kind != "span"]
+        for i, e in enumerate(evs):
             a = ",".join(f"{k}={e.attrs[k]!r}" for k in sorted(e.attrs))
-            lines.append(f"{e.seq}|{e.ts!r}|{e.kind}|{e.rid}|{e.slot}|{a}")
+            lines.append(f"{i}|{e.ts!r}|{e.kind}|{e.rid}|{e.slot}|{a}")
         return "\n".join(lines)
+
+
+@contextlib.contextmanager
+def span(tracer: Optional[Tracer], name: str, clock: Callable[[], float],
+         **attrs: Any) -> Iterator[None]:
+    """Time one host phase as ``name``.
+
+    Always a ``jax.profiler.TraceAnnotation``, which records nothing
+    unless a profiler session is active; in a session it lands on the
+    host plane, on the device trace's clock.  With a ``tracer``, also
+    one ``span`` event on exit: ``ts`` = start on ``clock``, attrs
+    ``name``, ``dur`` and ``attrs``.  Without one it reads no clock."""
+    with jax.profiler.TraceAnnotation(name):
+        if tracer is None:
+            yield
+            return
+        t0 = clock()
+        try:
+            yield
+        finally:
+            tracer.record("span", t0, name=name, dur=clock() - t0, **attrs)
+
+
+def watch_gc(owner: Any, tracer: Tracer,
+             clock: Callable[[], float]) -> None:
+    """Record every collection of Python's cyclic collector, while
+    ``owner`` lives, as a ``span`` event named ``gc`` (attrs
+    ``generation``, ``dur``, ``collected`` and ``chunk`` =
+    ``owner.chunks``) and, in a profiler session, as the host span
+    ``gc.gen<n>``.  The hook holds ``owner`` weakly and leaves
+    ``gc.callbacks`` when ``owner`` is collected."""
+    ref = weakref.ref(owner)
+    opened: List[Tuple[Any, float]] = []
+
+    def hook(phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            ann = jax.profiler.TraceAnnotation(f"gc.gen{info['generation']}")
+            ann.__enter__()
+            opened.append((ann, clock()))
+        elif opened:
+            ann, t0 = opened.pop()
+            ann.__exit__(None, None, None)
+            alive = ref()
+            if alive is not None:
+                tracer.record("span", t0, name="gc", dur=clock() - t0,
+                              generation=info["generation"],
+                              collected=info["collected"],
+                              chunk=alive.chunks)
+
+    gc.callbacks.append(hook)
+    weakref.finalize(owner, _unhook_gc, hook)
+
+
+def _unhook_gc(hook: Callable) -> None:
+    if hook in gc.callbacks:
+        gc.callbacks.remove(hook)
 
 
 def _lifecycle_phases(evs: List[TraceEvent]) -> List[
@@ -170,8 +247,10 @@ def to_chrome_trace(events: Iterable[TraceEvent], *,
     async ``b``/``e`` pairs keyed by rid; one ``s``/``t``/``f`` flow
     chain per request links submit through every admit/preempt hop to
     its terminal event; chunk-boundary counter samples become ``C``
-    counter tracks.  ``benchmarks/check_trace.py`` validates the
-    result against the trace-event schema.
+    counter tracks; ``span`` events become ``X`` events, host phases
+    on the engine track and collector pauses (``gc.gen<n>``) on a
+    ``gc`` track.  ``benchmarks/check_trace.py`` validates the result
+    against the trace-event schema.
     """
     evs = sorted(events, key=lambda e: e.seq)
     t0 = min((e.ts for e in evs), default=0.0)
@@ -256,7 +335,21 @@ def to_chrome_trace(events: Iterable[TraceEvent], *,
                 out.append(dict(base, ph="f", bp="e", ts=us(last[0]),
                                 tid=last[1]))
 
+    if any(e.kind == "span" and e.attrs.get("name") == "gc" for e in evs):
+        out.append({"ph": "M", "pid": pid, "tid": GC_TID,
+                    "name": "thread_name", "args": {"name": "gc"}})
     for e in evs:
+        if e.kind == "span":
+            args = {k: v for k, v in e.attrs.items()
+                    if k not in ("name", "dur")}
+            gc_span = e.attrs["name"] == "gc"
+            out.append({"ph": "X", "pid": pid,
+                        "tid": GC_TID if gc_span else ENGINE_TID,
+                        "ts": us(e.ts), "dur": e.attrs["dur"] * 1e6,
+                        "name": (f"gc.gen{e.attrs['generation']}"
+                                 if gc_span else e.attrs["name"]),
+                        "cat": "host", "args": args})
+            continue
         if e.kind == "chunk":
             for cname, akey in _COUNTER_TRACKS:
                 if akey in e.attrs:

@@ -5,9 +5,16 @@ non-terminal events while terminal events survive by contract; seeded
 ``TrafficGenerator`` replays under a ``VirtualClock`` produce
 byte-identical trace fingerprints; ``Engine.observe()`` emits only
 registry-known dotted names; ``export_trace`` / ``explain`` render
-complete submit->terminal chains."""
+complete submit->terminal chains; the engine's phase spans match the
+host plane of JAX's profiler, the ``chunk`` row counters add up to what
+was admitted and drained, and collections are ``gc`` spans only while a
+traced engine lives."""
 
+import gc
+import glob
 import json
+import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +25,8 @@ from repro.models import model_defs
 from repro.models import module as m
 from repro.serve import metrics
 from repro.serve.engine import Engine, Request
-from repro.serve.trace import TERMINAL_KINDS, Tracer, to_chrome_trace
+from repro.serve.trace import (ENGINE_TID, GC_TID, TERMINAL_KINDS, Tracer,
+                               to_chrome_trace)
 from repro.serve.traffic import TrafficGenerator, VirtualClock, replay
 
 
@@ -257,3 +265,231 @@ def test_chrome_trace_preempt_flow_spans_slot_hop():
     assert [w["args"]["phase"] for w in waits] == ["queued", "requeued"]
     runs = [e for e in evs if e["ph"] == "X"]
     assert len(runs) == 2 and all(e["dur"] > 0 for e in runs)
+
+
+# ---------------------------------------------------------------------------
+# Host phase spans on the profiler's clock, chunk row counters, gc spans
+# ---------------------------------------------------------------------------
+
+PHASES = ("engine.submit", "engine.reap", "engine.admit", "engine.dispatch",
+          "engine.wait", "engine.drain")
+
+
+def _host_events(log_dir):
+    """``[(name, start_ns, dur_ns)]`` of the profile's host-plane events
+    named like an engine phase or a collection, by start."""
+    files = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 1, files
+    pd = jax.profiler.ProfileData.from_file(files[0])
+    out = [(e.name, e.start_ns, e.duration_ns)
+           for plane in pd.planes if plane.name.startswith("/host:")
+           for line in plane.lines for e in line.events
+           if e.name.startswith(("engine.", "gc."))]
+    return sorted(out, key=lambda x: x[1])
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """A traced tiny engine served under JAX's profiler (host spans
+    only, as the benchmark records), a forced collection at the end;
+    every ``jax.device_get`` counted."""
+    cfg, params = _model("internlm2-1.8b")
+    eng = Engine(cfg, params, slots=2, max_len=64, sync_interval=4,
+                 trace=True, clock=time.perf_counter)
+    eng.warmup()
+    log_dir = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    gets = []
+    real_get = jax.device_get
+    steps = []
+    gc.collect()      # engines of earlier tests leave gc.callbacks now
+    mark = len(eng.tracer.events())
+    gc.disable()      # the one collection inside is the forced one
+    try:
+        with pytest.MonkeyPatch.context() as mp, \
+                jax.transfer_guard_device_to_host("disallow"), \
+                jax.profiler.trace(log_dir, profiler_options=opts):
+            mp.setattr(jax, "device_get",
+                       lambda x: gets.append(1) or real_get(x))
+            for i in range(4):
+                eng.submit(Request(rid=i, prompt=[(5 * i + j) % 150 + 1
+                                                  for j in range(3 + i)],
+                                   max_new_tokens=6 + i))
+            while eng.queue or eng._live():
+                c0, t0 = eng.chunks, time.perf_counter()
+                eng.step()
+                steps.append((c0, t0, time.perf_counter(),
+                              eng.chunks > c0))
+            gc.collect()
+    finally:
+        gc.enable()
+    ring = eng.tracer.events()[mark:]
+    return {"eng": eng, "ring": [e for e in ring if e.kind == "span"],
+            "host": _host_events(log_dir), "steps": steps,
+            "device_gets": len(gets)}
+
+
+@pytest.mark.parametrize("check", ["names_and_order", "durations",
+                                   "step_coverage", "gc", "sync_free"])
+def test_engine_spans_match_the_profilers_host_plane(profiled, check):
+    """Every engine phase is one ``span`` event in the ring and one host
+    span in JAX's profiler, on the clock the device trace uses: same
+    count, names and order, durations within 1 ms or 10%, start offsets
+    within 1 ms; the phases cover each ``step()``; the forced collection
+    is one ``gc`` span and one ``gc.gen2`` host span; and none of it adds
+    a device-to-host transfer or an executable."""
+    eng = profiled["eng"]
+    ring = [e for e in profiled["ring"] if e.attrs["name"] != "gc"]
+    ring.sort(key=lambda e: e.ts)
+    host = [h for h in profiled["host"] if h[0].startswith("engine.")]
+    if check == "names_and_order":
+        assert {e.attrs["name"] for e in ring} == set(PHASES)
+        assert [h[0] for h in host] == [e.attrs["name"] for e in ring]
+    elif check == "durations":
+        assert len(host) == len(ring)
+        for (name, start_ns, dur_ns), e in zip(host, ring):
+            d = dur_ns / 1e9
+            assert abs(d - e.attrs["dur"]) <= max(1e-3, 0.1 * d), name
+            offset = (start_ns - host[0][1]) / 1e9
+            assert abs(offset - (e.ts - ring[0].ts)) <= 1e-3, name
+    elif check == "step_coverage":
+        ran = [s for s in profiled["steps"] if s[3]]
+        assert len(ran) == eng.chunks >= 3
+        for c0, t0, t1, _ in ran:
+            phases = [e for e in ring if e.attrs["chunk"] == c0
+                      and e.attrs["name"] != "engine.submit"]
+            assert [e.attrs["name"] for e in phases] == list(PHASES[1:])
+            assert sum(e.attrs["dur"] for e in phases) >= 0.95 * (t1 - t0)
+    elif check == "gc":
+        gcs = [e for e in profiled["ring"] if e.attrs["name"] == "gc"]
+        assert len(gcs) == 1 and gcs[0].attrs["generation"] == 2
+        assert gcs[0].attrs["chunk"] == eng.chunks
+        host_gc = [h for h in profiled["host"] if h[0].startswith("gc.")]
+        assert [h[0] for h in host_gc] == ["gc.gen2"]
+        d = host_gc[0][2] / 1e9
+        assert abs(d - gcs[0].attrs["dur"]) <= max(1e-3, 0.1 * d)
+    else:
+        assert profiled["device_gets"] == eng.host_syncs == eng.chunks
+        assert eng.decode_compiles == 1
+
+
+@pytest.mark.parametrize("chunked_prefill", [True, False])
+def test_chunk_events_count_rows_run_prefilled_and_decoded(chunked_prefill):
+    """Per-chunk row counters, checked against what the run admitted and
+    drained: the prompt rows written are every admission's ``plen -
+    suffix_start`` (prefix hits included), rows that decoded plus one
+    prefill-sampled first token per admission are the tokens drained,
+    and every chunk ran ``slots x chunk_rows x sync_interval`` rows."""
+    cfg, params = _model("internlm2-1.8b")
+    eng = Engine(cfg, params, slots=3, max_len=96, page_size=8,
+                 sync_interval=4, prefill_budget=8,
+                 chunked_prefill=chunked_prefill, trace=True)
+    head = [(7 * j) % 150 + 1 for j in range(20)]
+    for wave in range(2):          # the second wave hits the first's pages
+        for i in range(3):
+            rid = 3 * wave + i
+            eng.submit(Request(rid=rid, prompt=head + [rid + 1] * (2 + i),
+                               max_new_tokens=5 + i))
+        done = eng.run(max_steps=eng.steps + 10_000)
+    evs = eng.tracer.events()
+    admits = [e for e in evs if e.kind == "admit"]
+    chunks = [e for e in evs if e.kind == "chunk"]
+    assert len(admits) == len(done) == 6 and len(chunks) == eng.chunks
+    assert not any(e.kind == "preempt" for e in evs)
+    if chunked_prefill:
+        assert sum(e.attrs["suffix_start"] for e in admits) > 0
+        assert sum(e.attrs["prefill_rows"] for e in chunks) == sum(
+            e.attrs["plen"] - e.attrs["suffix_start"] for e in admits)
+    else:
+        assert all(e.attrs["prefill_rows"] == 0 for e in chunks)
+    assert sum(e.attrs["decode_rows"] for e in chunks) + len(admits) == sum(
+        len(r.out_tokens) for r in done)
+    rows = 3 * eng.executor.chunk_rows * eng.sync_interval
+    assert {e.attrs["rows_run"] for e in chunks} == {rows}
+    assert rows == 3 * (8 if chunked_prefill else 1) * 4
+
+
+def test_gc_spans_only_with_a_tracer_and_the_hook_leaves_with_the_engine():
+    """A forced collection is one ``gc`` span in a traced engine's ring;
+    an untraced engine installs no hook; deleting the traced engine
+    takes its hook out of ``gc.callbacks``."""
+    cfg, params = _model("internlm2-1.8b")
+    gc.collect()
+    before = list(gc.callbacks)
+    bare = Engine(cfg, params, slots=1, max_len=64)
+    assert gc.callbacks == before
+    eng = Engine(cfg, params, slots=1, max_len=64, trace=True)
+    hooks = [h for h in gc.callbacks if h not in before]
+    assert len(hooks) == 1
+
+    def gc_spans():
+        return [e for e in eng.tracer.events()
+                if e.kind == "span" and e.attrs["name"] == "gc"]
+
+    gc.disable()
+    try:
+        n0 = len(gc_spans())
+        gc.collect()
+        spans = gc_spans()
+    finally:
+        gc.enable()
+    assert len(spans) == n0 + 1
+    last = spans[-1]
+    assert last.attrs["generation"] == 2 and last.attrs["dur"] >= 0
+    assert last.attrs["collected"] >= 0 and last.attrs["chunk"] == 0
+    del bare, eng, spans, last
+    gc.collect()
+    assert hooks[0] not in gc.callbacks
+    assert gc.callbacks == before
+
+
+def test_chrome_trace_draws_spans_on_the_engine_and_gc_tracks():
+    """Phase spans are complete events on the engine track, collections
+    on a ``gc`` track of their own; the export passes the schema check
+    and the fingerprint leaves spans out."""
+    from benchmarks.check_trace import validate
+
+    tr = Tracer()
+    tr.record("submit", 0.0, rid=1)
+    tr.record("span", 0.0, name="engine.submit", dur=0.001, chunk=0)
+    tr.record("admit", 0.5, rid=1, slot=0, chunk=0)
+    tr.record("span", 0.5, name="engine.admit", dur=0.002, chunk=0)
+    tr.record("span", 0.6, name="gc", dur=0.25, generation=2, collected=3,
+              chunk=0)
+    tr.record("finish", 1.0, rid=1, slot=0, status="FINISHED")
+    obj = to_chrome_trace(tr.events())
+    assert validate(obj) == []
+    xs = [e for e in obj["traceEvents"] if e["ph"] == "X"
+          and e["cat"] == "host"]
+    assert [(e["name"], e["tid"], e["dur"]) for e in xs] == [
+        ("engine.submit", ENGINE_TID, 1000.0),
+        ("engine.admit", ENGINE_TID, 2000.0),
+        ("gc.gen2", GC_TID, 250000.0)]
+    assert xs[2]["args"] == {"generation": 2, "collected": 3, "chunk": 0}
+    names = {e["tid"]: e["args"]["name"] for e in obj["traceEvents"]
+             if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert names[GC_TID] == "gc"
+    plain = Tracer()
+    for e in tr.events():
+        if e.kind != "span":
+            plain.record(e.kind, e.ts, rid=e.rid, slot=e.slot, **e.attrs)
+    assert tr.fingerprint() == plain.fingerprint()
+
+
+def test_traced_engine_export_passes_the_schema_check():
+    """An engine's own export, spans and collections included, passes
+    ``benchmarks/check_trace.py``."""
+    from benchmarks.check_trace import validate
+
+    cfg, params = _model("internlm2-1.8b")
+    eng = Engine(cfg, params, slots=2, max_len=64, trace=True,
+                 clock=time.perf_counter)
+    _workload(eng, n=4)
+    gc.collect()
+    obj = eng.export_trace()
+    assert validate(obj) == []
+    host = {e["name"] for e in obj["traceEvents"]
+            if e["ph"] == "X" and e.get("cat") == "host"}
+    assert host >= set(PHASES) | {"gc.gen2"}
