@@ -13,6 +13,14 @@ a 2-deep VMEM ring: while page ``j`` is being scored, page ``j+1``'s
 copy is already in flight (double buffering), so the DMA latency hides
 behind the flash-style online-softmax compute.
 
+Only the table entries that can hold a valid token are streamed: a
+slot's page loop runs ``live_blocks(cache_len, page_size, nb)`` times
+(``ceil(cache_len / page_size)`` until the ring wraps, the whole table
+after), so the pages past a slot's length — reserved for tokens not yet
+written, or trash — are neither copied nor scored.  Every query row
+sits at a position ``<= cache_len - 1``, so the bound is exact for
+sliding windows and multi-row verify steps too.
+
 **Quantized pools** (``k_scale``/``v_scale`` given): K/V pages are
 stored 8-bit (int8 / fp8_e4m3) with per-page, per-kv-head fp32 scales in
 a parallel scale pool.  The wrapper gathers each slot's scales through
@@ -52,11 +60,25 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
 NEG_INF = -1e30
+
+
+def live_blocks(cache_len, page_size: int, nb: int):
+    """Page-table entries that can hold a valid token of a slot with
+    ``cache_len`` tokens (the newest included) in a ``nb``-entry ring:
+    ``ceil(cache_len / page_size)``, at most ``nb`` (the ring has
+    wrapped), 0 for an empty slot.  Works on a traced scalar (the
+    kernel's page-loop bound) and on host integers or numpy arrays (the
+    engine's per-chunk count of pages streamed)."""
+    n = (cache_len + page_size - 1) // page_size
+    if isinstance(n, jax.Array):
+        return jnp.clip(n, 0, nb)
+    return np.clip(n, 0, nb)
 
 
 def _kernel(pt_ref, cl_ref, q_ref, kp_ref, vp_ref, *rest, page_size: int,
@@ -84,13 +106,17 @@ def _kernel(pt_ref, cl_ref, q_ref, kp_ref, vp_ref, *rest, page_size: int,
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    for c in page_copies(0, 0):          # warm the pipeline: page 0
-        c.start()
+    n_live = live_blocks(cl_ref[b], page_size, nb)   # entries streamed
+
+    @pl.when(n_live > 0)
+    def _warm():                         # warm the pipeline: page 0
+        for c in page_copies(0, 0):
+            c.start()
 
     def body(j, _):
         slot = jax.lax.rem(j, 2)
 
-        @pl.when(j + 1 < nb)
+        @pl.when(j + 1 < n_live)
         def _prefetch():                 # overlap: start page j+1 now
             for c in page_copies(j + 1, jax.lax.rem(j + 1, 2)):
                 c.start()
@@ -155,7 +181,7 @@ def _kernel(pt_ref, cl_ref, q_ref, kp_ref, vp_ref, *rest, page_size: int,
 
         return _
 
-    jax.lax.fori_loop(0, nb, body, None)
+    jax.lax.fori_loop(0, n_live, body, None)
     l = jnp.maximum(l_ref[...], 1e-30)
     o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 

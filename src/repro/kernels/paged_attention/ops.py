@@ -5,8 +5,10 @@ on the serving fast path when the engine's ``paged_kernel`` flag is on.
 Dispatch:
 
 * **TPU** — the compiled Pallas kernel (``kernel.paged_decode_attention``):
-  scalar-prefetch page tables, one K/V page DMA'd per grid step, online
-  softmax in VMEM scratch.  No gathered ring buffer exists at any point.
+  scalar-prefetch page tables, one grid step per slot that DMAs only the
+  slot's live pages (``kernel.live_blocks``: the entries below its
+  length), online softmax in VMEM scratch.  No gathered ring buffer
+  exists at any point.
 * **other backends** — ``pool_attention_xla`` below: score the query
   against the *entire* pool and mask by a scattered table-membership
   mask.  Still gather-free (the only scatter is a tiny ``[B, num_pages+1,

@@ -56,6 +56,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.kernels.paged_attention.kernel import live_blocks
 from repro.models import (forward_decode, forward_prefill, forward_verify,
                           model_defs)
 from repro.models import module as m
@@ -1874,9 +1875,11 @@ class Engine:
         fetch = (toks, self.state["out_len"], self.state["active"],
                  [self._slot_first_tok[i] for i in range(self.slots)])
         with self._span("engine.wait"):
-            if self.chunked_prefill:
-                # also drain the prefill cursor (cache["len"], capped by
-                # the prompt length per slot below) in the SAME transfer
+            if self.chunked_prefill or (self.tracer is not None
+                                        and self.spec.has_paged):
+                # also drain cache["len"] in the SAME transfer: the
+                # prefill cursor (capped by the prompt length per slot
+                # below) and, traced, the lengths the kernel streamed at
                 toks_np, out_len, active, firsts, cache_len = \
                     jax.device_get(fetch + (self.cache["len"],))
             else:
@@ -1895,7 +1898,7 @@ class Engine:
         prefill = decode = 0
         for slot in live:
             first = 0
-            if cache_len is not None:
+            if self.chunked_prefill:
                 plen0, prev = self._slot_plen[slot], self._slot_seen_len[slot]
                 seen = min(int(cache_len[slot]), plen0)
                 prefill += max(seen - prev, 0)
@@ -1906,6 +1909,20 @@ class Engine:
                 n = min(k, int(np.count_nonzero(toks_np[:, slot] >= 0)))
                 decode += max(n - first, 0)
         return prefill, decode
+
+    def _kv_pages(self, cache_len) -> Dict[str, int]:
+        """``kv_pages_streamed`` / ``kv_pages_table`` for the ``chunk``
+        event: the K/V pages one paged-attention call over the widest
+        table reads at the lengths just drained (``live_blocks`` per
+        slot; an idle slot's length 0 counts as the 1 the kernel sees),
+        against the slots x table width it would read unbounded."""
+        if cache_len is None:
+            return {}
+        g = self.spec.widest_group
+        lens = np.maximum(np.asarray(cache_len), 1)
+        return {"kv_pages_streamed": int(np.sum(live_blocks(
+                    lens, self.spec.page_size, g.ring_blocks))),
+                "kv_pages_table": self.slots * g.ring_blocks}
 
     def _settle(self, toks_np, out_len, active, firsts, cache_len) -> None:
         """Host bookkeeping of one drain, over the fetched arrays."""
@@ -1927,7 +1944,8 @@ class Engine:
                         live_slots=len(live),
                         rows_run=(self.slots * self.executor.chunk_rows
                                   * self.executor.sync_interval),
-                        prefill_rows=prefill_rows, decode_rows=decode_rows)
+                        prefill_rows=prefill_rows, decode_rows=decode_rows,
+                        **self._kv_pages(cache_len))
         watchdog: List[int] = []
         for slot in live:
             req = self._slot_req[slot]

@@ -12,7 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.paged_attention import (paged_attention_ref,
+from repro.kernels.paged_attention import (live_blocks,
+                                           paged_attention_ref,
                                            paged_decode_attention,
                                            pool_attention_xla)
 
@@ -187,6 +188,88 @@ def test_multiquery_first_row_matches_single_query():
         pk, pv, pt, cl)
     np.testing.assert_allclose(np.asarray(multi[:, -1]),
                                np.asarray(single), rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------------------ live page bound
+_LB_PAGE, _LB_NB = 4, 4
+_LB_RING = _LB_PAGE * _LB_NB
+
+
+@pytest.mark.parametrize("q_len,window", [(1, None), (5, None), (5, 6)],
+                         ids=["q1", "q5", "q5-window"])
+@pytest.mark.parametrize("cache_len", [
+    0, 1, _LB_PAGE, _LB_PAGE + 1, _LB_RING - 1, _LB_RING, _LB_RING + 5])
+def test_live_blocks_covers_every_valid_page(cache_len, q_len, window):
+    """The kernel's page-loop bound reaches every table entry that holds
+    a valid position for any query row (so no live page is cut), and
+    until the ring wraps it reaches no further (so no dead page is
+    streamed).  Validity is the oracle's ring formula, per row."""
+    ps, nb, ring = _LB_PAGE, _LB_NB, _LB_RING
+    t = cache_len - 1
+    r = np.arange(ring)
+    u = t - ((t - r) % ring)
+    qpos = cache_len - q_len + np.arange(q_len)[:, None]
+    valid = (u >= 0) & (u <= qpos)
+    if window is not None:
+        valid &= u > qpos - window
+    pages = np.flatnonzero(valid.any(axis=0).reshape(nb, ps).any(axis=1))
+    needed = int(pages[-1]) + 1 if pages.size else 0
+    n = int(live_blocks(cache_len, ps, nb))
+    assert needed <= n <= nb
+    if cache_len < ring:               # not wrapped: tight, ceil(len / P)
+        assert n == needed == -(-cache_len // ps)
+    else:
+        assert n == nb
+    traced = jax.jit(lambda c: live_blocks(c, ps, nb))(jnp.int32(cache_len))
+    assert int(traced) == n
+
+
+@pytest.mark.parametrize("q_len", [1, 5])
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+def test_kernel_never_reads_pages_past_the_live_count(kv_dtype, q_len):
+    """Every table entry past a slot's live count points at a real page
+    full of NaN (for int8, NaN scale rows): the kernel's output stays
+    finite and bitwise equal to the same call with those entries on the
+    trash page, which in turn matches the oracle reading finite pages
+    in every entry.  Slots: empty, one-token, partial, exactly full,
+    wrapped."""
+    ps, nb, h, hkv = 4, 4, 4, 2
+    ring = ps * nb
+    cl_host = np.asarray([0, 1, 2 * ps + 3, ring, ring + 5], np.int32)
+    b = len(cl_host)
+    q, pk, pv, _ = _case(b, h, hkv, 16, ps, nb, 2 * b * nb, seed=41)
+    if q_len > 1:
+        q = jnp.repeat(q[:, None], q_len, axis=1) * (1 + jnp.arange(
+            q_len)[None, :, None, None] * 0.1)
+    trash = pk.shape[0] - 1
+    live = np.minimum(-(-cl_host // ps), nb)      # ceil(len / P), wrapped
+    dead = np.arange(nb)[None, :] >= live[:, None]          # [B, nb]
+    pt_live = np.arange(b * nb, dtype=np.int32).reshape(b, nb)  # distinct
+    pt_nan = np.where(dead, b * nb + pt_live, pt_live)      # NaN pages
+    pt_trash = np.where(dead, trash, pt_live)
+    nan_pages = jnp.asarray(np.unique(pt_nan[dead]))
+    kw = nan_kw = {}
+    if kv_dtype == "fp32":
+        pk = pk.at[nan_pages].set(jnp.nan)
+        pv = pv.at[nan_pages].set(jnp.nan)
+    else:
+        q, pk, pv, sk, sv, _ = _quantize_case((q, pk, pv, None), kv_dtype)
+        kw = {"k_scale": sk, "v_scale": sv}
+        nan_kw = {"k_scale": sk.at[nan_pages].set(jnp.nan),
+                  "v_scale": sv.at[nan_pages].set(jnp.nan)}
+    cl = jnp.asarray(cl_host)
+    got = paged_decode_attention(q, pk, pv, jnp.asarray(pt_nan), cl,
+                                 interpret=_interpret(), **nan_kw)
+    clean = paged_decode_attention(q, pk, pv, jnp.asarray(pt_trash), cl,
+                                   interpret=_interpret(), **kw)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+    # the oracle reads every entry, on finite pages: none past the live
+    # count may matter
+    want = paged_attention_ref(q, pk, pv, jnp.asarray(pt_live), cl, **kw)
+    np.testing.assert_allclose(np.asarray(clean), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(np.asarray(got)[0], 0.0)  # empty slot
 
 
 def test_model_paged_decode_step_kernel_vs_gather():

@@ -409,6 +409,42 @@ def test_chunk_events_count_rows_run_prefilled_and_decoded(chunked_prefill):
     rows = 3 * eng.executor.chunk_rows * eng.sync_interval
     assert {e.attrs["rows_run"] for e in chunks} == {rows}
     assert rows == 3 * (8 if chunked_prefill else 1) * 4
+    table = 3 * eng.spec.widest_group.ring_blocks
+    assert {e.attrs["kv_pages_table"] for e in chunks} == {table}
+    assert all(3 <= e.attrs["kv_pages_streamed"] <= table for e in chunks)
+
+
+def test_chunk_events_count_kv_pages_streamed():
+    """On a known schedule (two prompts streaming through a fused chunk
+    of 8-row prefill micro-steps, then decoding; the third slot idle),
+    each ``chunk`` event's ``kv_pages_streamed`` is the sum over slots
+    of ``ceil(cache_len / page_size)`` at the lengths after the chunk,
+    the idle slot counting 1, and ``kv_pages_table`` is slots x table
+    width."""
+    cfg, params = _model("internlm2-1.8b")
+    page, budget, micro = 8, 8, 4
+    eng = Engine(cfg, params, slots=3, max_len=96, page_size=page,
+                 sync_interval=micro, prefill_budget=budget,
+                 prefix_sharing=False, trace=True)
+    plens = (20, 11)
+    for rid, plen in enumerate(plens):
+        eng.submit(Request(rid=rid, prompt=[(5 * rid + j) % 150 + 1
+                                            for j in range(plen)],
+                           max_new_tokens=40))
+    for _ in range(5):                 # nobody finishes in 20 micro-steps
+        eng.step()
+    chunks = [e for e in eng.tracer.events() if e.kind == "chunk"]
+    assert len(chunks) == 5
+
+    def length(plen, steps):           # prefill micro-steps, then 1 a step
+        k = -(-plen // budget)
+        return budget * steps if steps < k else plen + steps - k
+
+    for c, e in enumerate(chunks, start=1):
+        lens = [length(p, micro * c) for p in plens]
+        assert e.attrs["kv_pages_streamed"] == sum(
+            -(-n // page) for n in lens) + 1, (c, lens)
+        assert e.attrs["kv_pages_table"] == 3 * (96 // page)
 
 
 def test_gc_spans_only_with_a_tracer_and_the_hook_leaves_with_the_engine():
